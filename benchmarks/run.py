@@ -973,6 +973,8 @@ def main() -> None:
                          "analytics artifact); merges a flight section "
                          "into BENCH_smoke.json")
     args = ap.parse_args()
+    from repro.utils.compile_cache import use_compile_cache
+    use_compile_cache()
 
     print("name,us_per_call,derived")
 

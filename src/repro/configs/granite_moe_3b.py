@@ -9,4 +9,8 @@ CONFIG = register(ArchConfig(
     name="granite-moe-3b-a800m", family="moe",
     n_layers=32, d_model=1536, n_heads=24, n_kv_heads=8, d_ff=512,
     vocab=49155, head_dim=64, n_experts=40, top_k=8, rope_theta=10000.0,
+    # n_experts / top_k: any expert may take every token, so no token is
+    # dropped — the reference routes dropless, and a prompt's experts then
+    # do not depend on what else shares its prefill
+    capacity_factor=5.0,
 ))

@@ -8,4 +8,5 @@ CONFIG = register(ArchConfig(
     n_layers=56, d_model=6144, n_heads=48, n_kv_heads=8, d_ff=16384,
     vocab=32768, head_dim=128, n_experts=8, top_k=2,
     sliding_window=4096, rope_theta=1000000.0,
+    capacity_factor=4.0,    # n_experts / top_k: dropless, as the reference
 ))

@@ -11,10 +11,11 @@ is a pure pytree of three arrays:
 
 ``walk`` probes both levels in parallel (fixed entry locations mean no
 serial dependency) and falls back to the identity mapping when the leaf is
-unallocated or the entry invalid.  For large batches on TPU it dispatches
-to the Pallas kernel (``kernels/irt_lookup``); otherwise it runs the
-pure-jnp reference — the same oracle the kernel is tested against, so the
-two backends are interchangeable.
+unallocated or the entry invalid.  For large batches on TPU, over a table
+that fits the kernel's scalar memory, it dispatches to the Pallas kernel
+(``kernels/irt_lookup``); otherwise it runs the pure-jnp reference — the
+same oracle the kernel is tested against, so the two backends are
+interchangeable.
 
 ``fill`` / ``invalidate`` maintain entries + leaf counts and re-derive the
 level-1 bit vector from ``leaf_cnt > 0``.  (The seed kept l1 bits sticky
@@ -50,7 +51,10 @@ register(
 INVALID = -1
 E = 64                     # entries per leaf block (256 B / 4 B, Section 3.2)
 KERNEL_MIN_BATCH = 1024    # below this the gather is launch-overhead bound
-KERNEL_BLOCK = 512
+KERNEL_BLOCK = 1024        # ids per grid step: XLA tiles an s32 vector by
+                           # 1024, and an SMEM block must match that tiling
+KERNEL_MAX_TABLE = 1 << 17  # leaf entries held in SMEM: 512 KiB of the
+                            # v5e's 1 MiB, the rest holds the id blocks
 
 
 def n_words(n_leaf: int) -> int:
@@ -89,8 +93,9 @@ def walk(ids: jnp.ndarray, home: jnp.ndarray, l1_bits, entries,
 
     levels == 1 models a linear (always-allocated) table: only the entry
     validity is checked.  impl: "auto" picks the Pallas kernel for large
-    batches on TPU and the jnp reference elsewhere; "ref" / "kernel" force
-    a backend ("kernel" runs in interpret mode off-TPU, for tests).
+    batches over a table of at most ``KERNEL_MAX_TABLE`` entries on TPU
+    and the jnp reference elsewhere; "ref" / "kernel" force a backend
+    ("kernel" runs in interpret mode off-TPU, for tests).
     """
     (N,) = ids.shape
     if levels == 1:
@@ -98,7 +103,8 @@ def walk(ids: jnp.ndarray, home: jnp.ndarray, l1_bits, entries,
                          home).astype(jnp.int32)
     on_tpu = jax.default_backend() == "tpu"
     use_kernel = impl == "kernel" or (
-        impl == "auto" and on_tpu and N >= KERNEL_MIN_BATCH)
+        impl == "auto" and on_tpu and N >= KERNEL_MIN_BATCH
+        and entries.shape[0] <= KERNEL_MAX_TABLE)
     if not use_kernel:
         return irt_lookup_ref(ids, home, l1_bits, entries)
     bn = min(KERNEL_BLOCK, N)

@@ -134,8 +134,8 @@ def _fused_kernel(entries, pos,            # scalar prefetch
     for r in range(ktok):                      # static unroll over k tokens
         pg = p0 + r
         sel = (p0 >= 0) & (pg // page == j) & (row == pg % page)
-        k = jnp.where(sel, kn_ref[0, r, 0].astype(jnp.float32)[None, :], k)
-        v = jnp.where(sel, vn_ref[0, r, 0].astype(jnp.float32)[None, :], v)
+        k = jnp.where(sel, kn_ref[0, 0, r].astype(jnp.float32)[None, :], k)
+        v = jnp.where(sel, vn_ref[0, 0, r].astype(jnp.float32)[None, :], v)
 
     q = q_ref[0, 0].astype(jnp.float32)        # [ktok*group, hd]
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
@@ -197,7 +197,7 @@ def paged_attention(q, k_pool, v_pool, page_table, seq_lens, *,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, seq_lens, q, k_pool, v_pool)
@@ -250,7 +250,7 @@ def paged_attention_split(q, fast_k, fast_v, slow_k, slow_v, page_table,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, seq_lens, q, fast_k, fast_v, slow_k, slow_v)
@@ -267,15 +267,19 @@ def paged_attention_fused(q, fast_k, fast_v, slow_k, slow_v, entries,
 
     The index maps route each page's DMA straight off the leaf entries —
     no unified page table is ever materialised — and the new rows ride
-    in as [B,K,KV,hd] operands overlaid inside the kernel, so persisting
-    them to the pools happens off the critical path (batched scatter at
-    end of step) rather than as a dependency of the attention read."""
+    in as operands overlaid inside the kernel, so persisting them to the
+    pools happens off the critical path (batched scatter at end of step)
+    rather than as a dependency of the attention read."""
     B, K, KV, G, hd = q.shape
     page = fast_k.shape[2]
     npages = entries.shape[1]          # may be the live-page bucket
     np_total = slow_k.shape[0] // B    # identity-home stride (full table)
     scale = 1.0 / (hd ** 0.5)
     q2 = q.transpose(0, 2, 1, 3, 4).reshape(B, KV, K * G, hd)
+    # new rows head-major, [B, KV, K, hd]: a (1, 1, K, hd) block spans the
+    # array's last two dims, as Mosaic's tiling rule requires
+    kn2 = k_new.transpose(0, 2, 1, 3)
+    vn2 = v_new.transpose(0, 2, 1, 3)
 
     kernel = functools.partial(_fused_kernel, scale=scale, page=page,
                                npages=npages, ktok=K, group=G)
@@ -296,10 +300,10 @@ def paged_attention_fused(q, fast_k, fast_v, slow_k, slow_v, entries,
             pl.BlockSpec((1, 1, page, hd), _fast_idx),
             pl.BlockSpec((1, 1, page, hd), _slow_idx),
             pl.BlockSpec((1, 1, page, hd), _slow_idx),
-            pl.BlockSpec((1, K, 1, hd),
-                         lambda b, h, j, en, ps: (b, 0, h, 0)),
-            pl.BlockSpec((1, K, 1, hd),
-                         lambda b, h, j, en, ps: (b, 0, h, 0)),
+            pl.BlockSpec((1, 1, K, hd),
+                         lambda b, h, j, en, ps: (b, h, 0, 0)),
+            pl.BlockSpec((1, 1, K, hd),
+                         lambda b, h, j, en, ps: (b, h, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, K * G, hd),
                                lambda b, h, j, en, ps: (b, h, 0, 0)),
@@ -313,8 +317,8 @@ def paged_attention_fused(q, fast_k, fast_v, slow_k, slow_v, entries,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, KV, K * G, hd), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(entries, pos, q2, fast_k, fast_v, slow_k, slow_v, k_new, v_new)
+    )(entries, pos, q2, fast_k, fast_v, slow_k, slow_v, kn2, vn2)
     return out.reshape(B, KV, K, G, hd).transpose(0, 2, 1, 3, 4)

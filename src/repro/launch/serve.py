@@ -102,6 +102,9 @@ def main():
     from repro.configs import get_config, reduce_for_smoke
     from repro.models import init_params
     from repro.serve.engine import Engine, EngineConfig, Request
+    from repro.utils.compile_cache import use_compile_cache
+
+    use_compile_cache()
 
     cfg = get_config(args.arch)
     if args.smoke:

@@ -18,11 +18,13 @@ from __future__ import annotations
 import argparse
 import os
 
-# compute/collective overlap on real hardware (harmless on CPU)
-os.environ.setdefault(
-    "LIBTPU_INIT_ARGS",
-    "--xla_tpu_enable_async_collective_fusion=true "
-    "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true")
+# compute/collective overlap on real hardware (harmless on CPU).  Appended:
+# the TPU host may already set LIBTPU_INIT_ARGS, and its flags must stay
+_OVERLAP_FLAGS = ("--xla_tpu_enable_async_collective_fusion=true "
+                  "--xla_tpu_enable_async_collective_fusion_fuse_all_gather"
+                  "=true")
+os.environ["LIBTPU_INIT_ARGS"] = " ".join(
+    filter(None, (os.environ.get("LIBTPU_INIT_ARGS"), _OVERLAP_FLAGS)))
 
 
 def main():

@@ -28,11 +28,19 @@ def attn_init(key, cfg: ArchConfig, cross: bool = False) -> dict:
     d, hd, H, KV = cfg.d_model, cfg.hd, cfg.n_heads, cfg.n_kv_heads
     dt = jnp.dtype(cfg.dtype)
     ks = jax.random.split(key, 8)
+    # fan-in is d for the projections in and H*hd for the one out, not the
+    # second-minor dim dense_init would take: scaled by that, q.k grows
+    # with d/H and softmax saturates into a hard, chaotic argmax
+    s_in, s_out = d ** -0.5, (H * hd) ** -0.5
     p = {
-        "wq": dense_init(ks[0], (d, H, hd), ("embed", "heads", None), dt),
-        "wk": dense_init(ks[1], (d, KV, hd), ("embed", "kv_heads", None), dt),
-        "wv": dense_init(ks[2], (d, KV, hd), ("embed", "kv_heads", None), dt),
-        "wo": dense_init(ks[3], (H, hd, d), ("heads", None, "embed"), dt),
+        "wq": dense_init(ks[0], (d, H, hd), ("embed", "heads", None), dt,
+                         scale=s_in),
+        "wk": dense_init(ks[1], (d, KV, hd), ("embed", "kv_heads", None), dt,
+                         scale=s_in),
+        "wv": dense_init(ks[2], (d, KV, hd), ("embed", "kv_heads", None), dt,
+                         scale=s_in),
+        "wo": dense_init(ks[3], (H, hd, d), ("heads", None, "embed"), dt,
+                         scale=s_out),
     }
     if cfg.qkv_bias:
         p["bq"] = zeros_init((H, hd), ("heads", None), dt)
@@ -85,7 +93,11 @@ def _sdpa(q, k, v, mask):
     KV = k.shape[2]
     G = H // KV
     q = q.reshape(B, S, KV, G, hd)
-    scores = jnp.einsum("bskgh,btkh->bkgst", q, k).astype(jnp.float32)
+    # scores accumulate AND land in float32: rounded to bf16 they carry a
+    # half-ulp error that grows with their magnitude, which the softmax
+    # then exponentiates
+    scores = jnp.einsum("bskgh,btkh->bkgst", q, k,
+                        preferred_element_type=jnp.float32)
     scores = scores / jnp.sqrt(hd).astype(jnp.float32)
     if mask is not None:
         scores = scores + mask
@@ -117,7 +129,8 @@ def chunked_sdpa(q, k, v, *, causal: bool, window: int = 0,
         def k_block(carry, ki_kb):
             m, l, acc = carry
             ki, kb, vb = ki_kb
-            s = jnp.einsum("bqkgh,btkh->bkgqt", qb, kb).astype(jnp.float32)
+            s = jnp.einsum("bqkgh,btkh->bkgqt", qb, kb,
+                           preferred_element_type=jnp.float32)
             s = s * scale
             qpos = qi * qc + jnp.arange(qc)
             kpos = ki * kc + jnp.arange(kc)

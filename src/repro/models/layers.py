@@ -8,6 +8,8 @@ init tree are ``Param(value, axes)`` pairs split by ``split_tree``.
 
 from __future__ import annotations
 
+import zlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -56,8 +58,11 @@ def split_tree(tree):
 
 
 def key_for(key, *path) -> jax.Array:
+    # crc32, not hash(): str hashing is salted per process, so hash() would
+    # draw different weights from the same seed in every process
     for p in path:
-        key = jax.random.fold_in(key, hash(p) & 0x7FFFFFFF)
+        key = jax.random.fold_in(key,
+                                 zlib.crc32(str(p).encode()) & 0x7FFFFFFF)
     return key
 
 

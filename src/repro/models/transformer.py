@@ -86,11 +86,13 @@ def _block_init(key, cfg: ArchConfig, cross: bool = False) -> dict:
 
 
 def _stack_layers(key, cfg: ArchConfig, n: int, cross: bool = False):
-    layers = [_block_init(key_for(key, "layer", i), cfg, cross)
-              for i in range(n)]
-    return jax.tree.map(lambda *xs: Param(jnp.stack([x.value for x in xs]),
-                                          ("layers",) + xs[0].axes),
-                        *layers, is_leaf=lambda x: isinstance(x, Param))
+    # one vmapped init over the layer keys: each layer draws exactly what
+    # its own _block_init call would, and the program holds one block's
+    # ops instead of n copies
+    keys = jnp.stack([key_for(key, "layer", i) for i in range(n)])
+    layers = jax.vmap(lambda k: _block_init(k, cfg, cross))(keys)
+    return jax.tree.map(lambda p: Param(p.value, ("layers",) + p.axes),
+                        layers, is_leaf=lambda x: isinstance(x, Param))
 
 
 def _build_param_tree(cfg: ArchConfig, key) -> dict:
@@ -124,7 +126,12 @@ def init_params_and_axes(cfg: ArchConfig, key) -> tuple[dict, dict]:
     return split_tree(_build_param_tree(cfg, key))
 
 
+@functools.partial(jax.jit, static_argnums=0)
 def init_params(cfg: ArchConfig, key) -> dict:
+    """Parameter values drawn from ``key``, built as one compiled program:
+    built eagerly, ``_stack_layers`` holds every layer twice (the per-layer
+    arrays and their stack), which a full-width model cannot afford on
+    one chip."""
     return init_params_and_axes(cfg, key)[0]
 
 

@@ -12,8 +12,9 @@ from repro.kernels.irt_lookup.irt_lookup import E as LEAF_E
 from repro.kernels.irt_lookup.irt_lookup import irt_lookup
 from repro.kernels.irt_lookup.ref import irt_lookup_ref
 from repro.kernels.paged_attention.paged_attention import (
-    paged_attention, paged_attention_split)
-from repro.kernels.paged_attention.ref import (paged_attention_ref,
+    paged_attention, paged_attention_fused, paged_attention_split)
+from repro.kernels.paged_attention.ref import (paged_attention_fused_ref,
+                                               paged_attention_ref,
                                                paged_attention_split_ref)
 from repro.kernels.remap_gather.ops import remap_scatter_op
 from repro.kernels.remap_gather.remap_gather import remap_gather
@@ -176,6 +177,40 @@ def test_paged_attention_split_matches_concat_bitwise():
         np.asarray(paged_attention_split(q, fk, fv, sk, sv, pt, sl,
                                          interpret=True)),
         np.asarray(paged_attention(q, uk, uv, pt, sl, interpret=True)))
+
+
+@pytest.mark.parametrize("B,K,KV,G,hd,page,np_total,n_pages,fast_slots", [
+    (2, 1, 2, 3, 64, 16, 8, 8, 4),
+    (3, 4, 2, 2, 64, 16, 8, 4, 6),      # live-page bucket < table width
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_fused_sweep(B, K, KV, G, hd, page, np_total,
+                                     n_pages, fast_slots, dtype):
+    """Fused k-token append+attend kernel vs its oracle: fast-routed and
+    slow-home pages mixed, a parked lane, and new rows that overlay
+    positions inside the attended prefix."""
+    ks = [jax.random.fold_in(KEY, i) for i in range(8)]
+    q = jax.random.normal(ks[0], (B, K, KV, G, hd), dtype)
+    fk = jax.random.normal(ks[1], (fast_slots, KV, page, hd), dtype)
+    fv = jax.random.normal(ks[2], (fast_slots, KV, page, hd), dtype)
+    sk = jax.random.normal(ks[3], (B * np_total, KV, page, hd), dtype)
+    sv = jax.random.normal(ks[4], (B * np_total, KV, page, hd), dtype)
+    kn = jax.random.normal(ks[5], (B, K, KV, hd), dtype)
+    vn = jax.random.normal(ks[6], (B, K, KV, hd), dtype)
+    # distinct fast slots for a few pages, every other page at its home
+    entries = np.full((B, n_pages), -1, np.int32)
+    lanes = np.asarray(jax.random.permutation(ks[7], B * n_pages))
+    entries.reshape(-1)[lanes[:fast_slots]] = np.arange(fast_slots)
+    pos = np.asarray([n_pages * page - K - 3] + [5] * (B - 1), np.int32)
+    pos[-1] = -1                                    # parked lane
+    args = (q, fk, fv, sk, sv, jnp.asarray(entries), kn, vn,
+            jnp.asarray(pos))
+    out = paged_attention_fused(*args, interpret=True)
+    ref = paged_attention_fused_ref(*args)
+    live = pos >= 0
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(ref, np.float32)[live],
+                               **_tol(dtype))
 
 
 # ---------------------------------------------------------------------------

@@ -20,6 +20,10 @@ from repro.core.policy import PRESETS, get_policy
 from repro.serve import tiered as srv
 from repro.tiered import kvcache as tk
 
+# two float32 code paths over the same math agree to rounding, not bit for
+# bit: the misses seen on XLA:CPU were at most 1.3e-5 in magnitude
+F32_PATH_TOL = dict(rtol=1e-5, atol=5e-5)
+
 
 def _cfg(preset=None, **kw):
     base = dict(n_seqs=2, max_pages_per_seq=16, page_tokens=4,
@@ -106,9 +110,41 @@ def test_attend_tokens_parked_lane_reads_nothing():
     assert int(st2.touch[half:].sum()) == 0
 
 
+def _np_attend_tokens(cfg, st, q, kn, vn, pos):
+    """Plain float64 NumPy reference of the k-token read on a store with
+    no fast-resident page: each lane's pages from its identity slow
+    homes, the new rows overlaid at pos..pos+K-1, token i attending
+    positions < pos+1+i."""
+    B, K, KV, G, hd = q.shape
+    NP, P = cfg.max_pages_per_seq, cfg.page_tokens
+
+    def seq(pool, new):
+        x = np.asarray(pool, np.float64).reshape(B, NP, KV, P, hd)
+        x = x.transpose(0, 2, 1, 3, 4).reshape(B, KV, NP * P, hd).copy()
+        for b in range(B):
+            for i in range(K):
+                x[b, :, int(pos[b]) + i] = np.asarray(new[b, i], np.float64)
+        return x
+
+    k_seq, v_seq = seq(st.slow_k, kn), seq(st.slow_v, vn)
+    qq = np.asarray(q, np.float64)
+    out = np.zeros(q.shape)
+    for b in range(B):
+        for i in range(K):
+            n = int(pos[b]) + 1 + i
+            s = np.einsum("kgh,kth->kgt", qq[b, i], k_seq[b, :, :n])
+            w = np.exp(s / np.sqrt(hd) - (s / np.sqrt(hd)).max(-1,
+                                                               keepdims=True))
+            w /= w.sum(-1, keepdims=True)
+            out[b, i] = np.einsum("kgt,kth->kgh", w, v_seq[b, :, :n])
+    return out
+
+
 def test_attend_tokens_bucket_bitwise():
-    """The live-page attention bucket (n_pages) is bitwise-invisible:
-    same output AND same updated state as the full-width read, provided
+    """The live-page attention bucket (n_pages) is invisible: the bucketed
+    and the full-width read both agree with a NumPy reference within
+    F32_PATH_TOL (they reduce over different lengths, so not bit for
+    bit), and they leave the same updated state, bit for bit, provided
     every live/appended position fits in the bucket."""
     cfg = _cfg()
     key = jax.random.key(2)
@@ -118,17 +154,20 @@ def test_attend_tokens_bucket_bitwise():
     pos = jnp.asarray([9, 4], jnp.int32)           # fits in 4 pages of 4
     out_full, st_full = srv.attend_tokens(cfg, st, q, kn, vn, pos)
     out_b, st_b = srv.attend_tokens(cfg, st, q, kn, vn, pos, n_pages=4)
-    np.testing.assert_array_equal(np.asarray(out_full), np.asarray(out_b))
+    ref = _np_attend_tokens(cfg, st, q, kn, vn, np.asarray(pos))
+    np.testing.assert_allclose(np.asarray(out_full), ref, **F32_PATH_TOL)
+    np.testing.assert_allclose(np.asarray(out_b), ref, **F32_PATH_TOL)
     for a, b in zip(jax.tree.leaves(st_full), jax.tree.leaves(st_b)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
 
 
 def test_decode_step_bucket_logits_identical():
-    """Model level: decode_step with the live-page bucket produces
-    logits bitwise equal to the unbucketed step (same state stream)."""
+    """Model level: decode_step with the live-page bucket and the
+    unbucketed step both produce logits within F32_PATH_TOL of the dense
+    float32 reference fed the same token stream."""
     from repro.configs import get_config, reduce_for_smoke
     from repro.models import decode_step, init_params
-    from repro.models.kv_backend import TieredBackend
+    from repro.models.kv_backend import DenseBackend, TieredBackend
 
     cfg = reduce_for_smoke(get_config("llama3-8b"))
     params = init_params(cfg, jax.random.key(0))
@@ -142,11 +181,19 @@ def test_decode_step_bucket_logits_identical():
                                                     backend=bk))
     step_bkt = jax.jit(lambda p, s, t: decode_step(cfg, p, s, t,
                                                    backend=bk, n_pages=2))
+    dense = DenseBackend(cfg)
+    st_d = dense.init_state(B, max_len)
+    step_dense = jax.jit(lambda p, s, t: decode_step(cfg, p, s, t,
+                                                     backend=dense))
     for i in range(6):
         la, st_a = step_full(params, st_a, tok)
         lb, st_b = step_bkt(params, st_b, tok)
-        np.testing.assert_array_equal(np.asarray(la), np.asarray(lb))
-        tok = jnp.argmax(la, -1).astype(jnp.int32)
+        ld, st_d = step_dense(params, st_d, tok)
+        np.testing.assert_allclose(np.asarray(la), np.asarray(ld),
+                                   **F32_PATH_TOL)
+        np.testing.assert_allclose(np.asarray(lb), np.asarray(ld),
+                                   **F32_PATH_TOL)
+        tok = jnp.argmax(ld, -1).astype(jnp.int32)
 
 
 def test_engine_overlap_maintain_identity():

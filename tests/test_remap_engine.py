@@ -283,7 +283,7 @@ def test_walk_matches_ref_and_pads_ragged_batches():
                                    irt_ops.INVALID), jnp.int32)
     bits = jnp.asarray(rng.integers(-2**31, 2**31 - 1, -(-n_leaf // 32)),
                        jnp.int32)
-    for n in (7, 600):   # 600 > KERNEL_BLOCK exercises the padding path
+    for n in (7, 1500):  # 1500 > KERNEL_BLOCK exercises the padding path
         ids = jnp.asarray(rng.integers(0, n_leaf * irt_ops.E, n), jnp.int32)
         home = ids + 1000
         ref = irt_lookup_ref(ids, home, bits, entries)
