@@ -132,9 +132,9 @@ def _np_attend_tokens(cfg, st, q, kn, vn, pos):
     for b in range(B):
         for i in range(K):
             n = int(pos[b]) + 1 + i
-            s = np.einsum("kgh,kth->kgt", qq[b, i], k_seq[b, :, :n])
-            w = np.exp(s / np.sqrt(hd) - (s / np.sqrt(hd)).max(-1,
-                                                               keepdims=True))
+            s = np.einsum("kgh,kth->kgt", qq[b, i],
+                          k_seq[b, :, :n]) / np.sqrt(hd)
+            w = np.exp(s - s.max(-1, keepdims=True))
             w /= w.sum(-1, keepdims=True)
             out[b, i] = np.einsum("kgt,kth->kgh", w, v_seq[b, :, :n])
     return out
