@@ -1,18 +1,29 @@
-"""Structured step tracing: Chrome-trace-event JSON (Perfetto-loadable)
-spans for the serving loop's phases, plus optional ``jax.profiler``
-hooks for kernel-level timelines (DESIGN.md §10).
+"""Structured step tracing: one set of span sites for the serving loop's
+phases, with two sinks (DESIGN.md §10).
 
-Span semantics: a ``span`` measures the host-observed wall time of one
-engine phase — ``prefill_chunk`` / ``prefill`` / ``decode_step`` /
-``maintain`` / ``release`` — including the device sync the decode loop
-performs anyway (it reads every step's tokens back).  Per-span metric
-annotations ride in ``args`` and show up in Perfetto's span details.
+- The profiler.  Every span enters ``jax.profiler.TraceAnnotation(
+  "<cat>.<name>", **args)`` (``engine.*`` for the engine loop, ``sched.*``
+  for the scheduler), and each loop iteration is a
+  ``StepTraceAnnotation("engine.step", step_num=...)``.  These cost about a
+  microsecond when no profiler session is active and land on the
+  profiler's host plane, on the same clock as the device's operations,
+  whenever one is.  No ``ObsConfig`` is needed and donation is untouched.
+- Chrome-trace-event JSON (Perfetto-loadable), written by ``StepTracer``
+  when ``ObsConfig.trace_path`` is set.
+
+Span semantics: a span times the host side of one phase: the dispatch of
+its device programs plus whatever host work the phase does.  Dispatch
+returns before the device finishes, so a span waits on the device only
+where the phase reads from it: ``sync`` (the loop's two host reads of
+tokens and positions), ``bucket`` (its read of positions), the maintenance
+apply's counter snapshot, and the final prefill chunk's first-token read.
+Span arguments are host ints already at hand (step, lane, rid, tokens,
+pages); a span never reads a device value.
 
 Event schema (Trace Event Format, the subset Perfetto ingests):
   {"ph": "X", "name": ..., "cat": ..., "pid": 1, "tid": ...,
    "ts": <µs since tracer start>, "dur": <µs>, "args": {...}}     spans
   {"ph": "C", "name": ..., "ts": ..., "args": {metric: value}}  counters
-  {"ph": "i", "name": ..., "ts": ..., "s": "g"}                 instants
   {"ph": "M", ...}                                    process/thread names
 
 Open a saved trace at https://ui.perfetto.dev ("Open trace file") or
@@ -26,6 +37,10 @@ import contextlib
 import json
 import time
 
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+STEP_SPAN = "engine.step"
+
 
 class StepTracer:
     """Collects trace events in memory; ``save`` writes the JSON."""
@@ -34,6 +49,9 @@ class StepTracer:
     #: separate tids stack visually instead of overlapping
     TIDS = {"decode_step": 0, "prefill": 1, "prefill_chunk": 1,
             "admit_fast": 1, "maintain": 2, "release": 3}
+    #: the scheduler's spans (``cat="sched"``) render on a lane of their
+    #: own, so its ``release`` does not stack on the engine's
+    SCHED_TID = 4
 
     def __init__(self, process_name: str = "repro.serve.engine"):
         self._t0 = time.perf_counter()
@@ -42,7 +60,8 @@ class StepTracer:
              "args": {"name": process_name}},
         ]
         for name, tid in (("decode", 0), ("prefill", 1),
-                          ("maintain", 2), ("release", 3)):
+                          ("maintain", 2), ("release", 3),
+                          ("scheduler", self.SCHED_TID)):
             self.events.append(
                 {"ph": "M", "name": "thread_name", "pid": 1, "tid": tid,
                  "args": {"name": name}})
@@ -63,22 +82,36 @@ class StepTracer:
     @contextlib.contextmanager
     def span(self, name: str, cat: str = "engine", tid: int | None = None,
              **args):
-        """Complete-event span around one phase; ``args`` annotate it."""
+        """Complete-event span around one phase, also entered as the
+        profiler annotation ``<cat>.<name>``; ``args`` annotate both."""
+        if tid is None:
+            tid = self.SCHED_TID if cat == "sched" \
+                else self.TIDS.get(name, 0)
         ts = self.now_us()
         try:
-            yield
+            with TraceAnnotation(f"{cat}.{name}", **args):
+                yield
         finally:
             self.events.append({
                 "ph": "X", "name": name, "cat": cat, "pid": 1,
-                "tid": self.TIDS.get(name, 0) if tid is None else tid,
+                "tid": tid,
                 "ts": ts, "dur": self.now_us() - ts,
                 "args": args,
             })
 
-    def instant(self, name: str, cat: str = "engine", **args) -> None:
-        self.events.append({"ph": "i", "name": name, "cat": cat, "pid": 1,
-                            "tid": 0, "ts": self.now_us(), "s": "g",
-                            "args": args})
+    @contextlib.contextmanager
+    def step(self, step: int):
+        """One loop iteration: the profiler's step annotation and a
+        ``step`` span, the parent of every phase span in it."""
+        ts = self.now_us()
+        try:
+            with StepTraceAnnotation(STEP_SPAN, step_num=step):
+                yield
+        finally:
+            self.events.append({
+                "ph": "X", "name": "step", "cat": "engine", "pid": 1,
+                "tid": 0, "ts": ts, "dur": self.now_us() - ts,
+                "args": {"step": step}})
 
     def counter(self, name: str, values: dict,
                 ts: float | None = None) -> None:
@@ -96,22 +129,21 @@ class StepTracer:
 
 
 class NullTracer:
-    """No-op stand-in so the engine's hot loop stays branch-free: the
-    span context manager costs one attribute lookup when tracing is off."""
-
-    _NULL = contextlib.nullcontext()
+    """The tracer without a JSON sink: spans and steps still reach the
+    profiler (about a microsecond each when no session is active), so the
+    engine's hot loop stays branch-free."""
 
     def span(self, name, cat="engine", tid=None, **args):
-        return self._NULL
+        return TraceAnnotation(f"{cat}.{name}", **args)
+
+    def step(self, step):
+        return StepTraceAnnotation(STEP_SPAN, step_num=step)
 
     def clear(self):
         pass
 
     def now_us(self):
         return 0.0
-
-    def instant(self, *a, **k):
-        pass
 
     def counter(self, *a, **k):
         pass
@@ -127,9 +159,8 @@ NULL_TRACER = NullTracer()
 def profiler_trace(log_dir: str | None):
     """Optionally wrap a block in a ``jax.profiler`` trace: when
     ``log_dir`` is set, device-side activity (including the Pallas
-    kernels) lands in a TensorBoard/Perfetto-compatible trace under it;
-    ``None`` is a no-op.  Imported lazily — the profiler pulls in heavy
-    deps only when actually requested."""
+    kernels) lands in a TensorBoard/Perfetto-compatible trace under it,
+    beside the engine's spans; ``None`` is a no-op."""
     if not log_dir:
         yield
         return
@@ -139,16 +170,3 @@ def profiler_trace(log_dir: str | None):
         yield
     finally:
         jax.profiler.stop_trace()
-
-
-@contextlib.contextmanager
-def annotate(name: str, enabled: bool = True):
-    """Named ``jax.profiler`` annotation (shows up inside the profiler
-    timeline around the wrapped dispatches, e.g. the split-pool paged-
-    attention kernel).  No-op when disabled."""
-    if not enabled:
-        yield
-        return
-    import jax
-    with jax.profiler.TraceAnnotation(name):
-        yield

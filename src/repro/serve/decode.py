@@ -131,13 +131,14 @@ def make_chunk_prefill_fn(cfg: ArchConfig, *, logits: bool = False):
     """Build one jitted chunked-prefill step (DESIGN.md §9): one prompt
     chunk's K/V computed against the accumulated per-layer key buffers.
 
-    Returned signature: step(params, chunk_tokens [B, C], buf_k, buf_v,
-    start) -> (buf_k, buf_v) with rows [start, start + C) written.  The
-    buffers ([L, B, P, KV, hd], ``models.init_chunk_buffers``) must be
-    padded to the SAME length P the one-shot prefill forward would run
-    at — that is what makes every chunk's reductions (and therefore the
-    ingested K/V and all downstream decode logits) bit-identical to the
-    one-shot ``forward(collect_cache=True)`` pass.  One jit key covers
+    Returned signature: engine_chunk_fwd(params, chunk_tokens [B, C],
+    buf_k, buf_v, start) -> (buf_k, buf_v) with rows [start, start + C)
+    written.  The buffers ([L, B, P, KV, hd],
+    ``models.init_chunk_buffers``) must be padded to the SAME length P the
+    one-shot prefill forward would run at — that is what makes every
+    chunk's reductions (and therefore the ingested K/V and all downstream
+    decode logits) bit-identical to the one-shot
+    ``forward(collect_cache=True)`` pass.  One jit key covers
     every (P, C) pair the caller uses it at (shapes re-trace as usual).
 
     ``logits=True`` appends the chunk's LM-head logits [B, C, vocab] to
@@ -147,11 +148,11 @@ def make_chunk_prefill_fn(cfg: ArchConfig, *, logits: bool = False):
     """
     from repro.models import forward_chunk
 
-    def step(params, chunk_tokens, buf_k, buf_v, start):
+    def engine_chunk_fwd(params, chunk_tokens, buf_k, buf_v, start):
         return forward_chunk(cfg, params, chunk_tokens, buf_k, buf_v,
                              start, return_logits=logits)
 
-    return jax.jit(step)
+    return jax.jit(engine_chunk_fwd)
 
 
 def make_prefill_fn(cfg: ArchConfig, shape: ShapeConfig):

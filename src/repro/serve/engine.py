@@ -150,10 +150,11 @@ class EngineConfig:
     tenants: tuple = ()           # TenantConfig per tenant (empty: one
                                   # default tenant)
     starvation_bound: int = 8     # QoS: max admission skips in a row
-    # observability (DESIGN.md §10): None = metrics/tracing fully off (the
-    # decode loop stays span- and sample-free); an ObsConfig turns on
-    # periodic MetricsHub samples and, when paths are set, the Prometheus
-    # exposition / JSONL series / Perfetto trace written at drain
+    # observability (DESIGN.md §10): None = no metrics and no JSON trace
+    # (the loop's spans still reach an active jax.profiler session); an
+    # ObsConfig turns on periodic MetricsHub samples and, when paths are
+    # set, the Prometheus exposition / JSONL series / Perfetto trace
+    # written at drain
     obs: ObsConfig | None = None
     # page-lifecycle flight recorder (obs/flight, DESIGN.md §12): a
     # FlightConfig turns on the in-graph event ring (tiered backend
@@ -285,23 +286,43 @@ class Engine:
         # out — its samples stash references into the state across steps
         # (the batched drain tap would read donated buffers)
         self._donate = ec.obs is None
+        # every program the loop dispatches is a named function: the
+        # profiler calls its module ``jit_<name>``, so a device trace sums
+        # time per program (DESIGN.md §10)
         if self._tiered:
-            self._maintain = jax.jit(self.backend.maintain)
-            self._release = jax.jit(self.backend.release)
-            self._plan_fn = jax.jit(
-                lambda s: self.backend.plan_maintain(s))
-            self._apply_fn = jax.jit(
-                lambda s, p: self.backend.apply_maintain(s, p))
+            backend = self.backend
+
+            def engine_maintain(s):
+                return backend.maintain(s)
+
+            def engine_release(s, lane):
+                return backend.release(s, lane)
+
+            def engine_maintain_plan(s):
+                return backend.plan_maintain(s)
+
+            def engine_maintain_apply(s, p):
+                return backend.apply_maintain(s, p)
+
+            self._maintain = jax.jit(engine_maintain)
+            self._release = jax.jit(engine_release)
+            self._plan_fn = jax.jit(engine_maintain_plan)
+            self._apply_fn = jax.jit(engine_maintain_apply)
         self._pending_plan = None      # double-buffered maintain (§11)
         self.maintain_overlaps = 0
         self._prefill_fns: dict[int, Callable] = {}
         self._chunk_fns: dict[tuple, Callable] = {}
         self._write_chunk_fns: dict[int, Callable] = {}
         self._admit_fns: dict[int, Callable] = {}
-        self._set_pos = jax.jit(
-            lambda s, i, v: s._replace(pos=s.pos.at[i].set(v)))
-        self._mask_idle = jax.jit(
-            lambda s, m: s._replace(pos=jnp.where(m, -1, s.pos)))
+
+        def engine_set_pos(s, i, v):
+            return s._replace(pos=s.pos.at[i].set(v))
+
+        def engine_park_idle(s, m):
+            return s._replace(pos=jnp.where(m, -1, s.pos))
+
+        self._set_pos = jax.jit(engine_set_pos)
+        self._mask_idle = jax.jit(engine_park_idle)
         self.releases = 0
         self.steps = 0
         self._bw_log: list = []        # per-maintain counter snapshots
@@ -309,8 +330,9 @@ class Engine:
         self.scheduler = scheduler if scheduler is not None \
             else make_scheduler(ec)
         self.scheduler.bind(self)
-        # observability (DESIGN.md §10): hub + tracer only when configured;
-        # NULL_TRACER keeps the hot loop's span sites branch-free.  A
+        # observability (DESIGN.md §10): hub + JSON tracer only when
+        # configured; NULL_TRACER sends the same span sites to the profiler
+        # alone, so the hot loop stays branch-free.  A
         # sample inside the loop only stashes array references (tap_stash)
         # — the batched jitted tap turns ALL samples' counter reductions
         # into one compiled call + one transfer at drain
@@ -391,11 +413,14 @@ class Engine:
         attention bucket (one retrace per power-of-two bucket — at most
         log2(max_pages_per_seq) keys over a run)."""
         if n_pages not in self._step_fns:
-            cfg = self.cfg
+            cfg, backend = self.cfg, self.backend
+
+            def engine_decode(p, s, t):
+                return decode_step(cfg, p, s, t, backend=backend,
+                                   n_pages=n_pages)
+
             self._step_fns[n_pages] = jax.jit(
-                lambda p, s, t, np_=n_pages: decode_step(
-                    cfg, p, s, t, backend=self.backend, n_pages=np_),
-                donate_argnums=(1,) if self._donate else ())
+                engine_decode, donate_argnums=(1,) if self._donate else ())
         return self._step_fns[n_pages]
 
     def _live_bucket(self, state) -> int | None:
@@ -406,9 +431,10 @@ class Engine:
         suffice; the power-of-two rounding keeps the jit key count at
         log2.  None (full provisioned width) when bucketing is off, the
         backend is dense, every lane is parked, or the bucket already
-        spans the whole table.  ``state.pos`` here is the PREVIOUS step's
-        output, already materialised by the harvest loop's host read, so
-        this costs one tiny transfer, not a pipeline stall."""
+        spans the whole table.  ``state.pos`` here may be the output of
+        the refill's programs (``set_pos``, ``park_idle``) or of the
+        deferred maintenance apply, so this host read waits for them: it
+        runs in the ``bucket`` span."""
         if not (self._tiered and self.ec.page_bucket):
             return None
         mx = int(np.asarray(state.pos).max())
@@ -434,7 +460,7 @@ class Engine:
         backend = self.backend
         mpp = backend.tcfg.max_pages_per_seq
 
-        def fn(state, plan, fl, step, lane_tenant):
+        def engine_maintain_apply_rec(state, plan, fl, step, lane_tenant):
             touch0 = state.caches.touch[0]
             state, ddesc, pdesc = backend.apply_maintain_desc(state, plan)
 
@@ -455,7 +481,7 @@ class Engine:
                      pdesc["cb2_dst"], pdesc["cb2_en"])
             return state, fl
 
-        return fn
+        return engine_maintain_apply_rec
 
     def _make_rec_release(self):
         """Build the fused record+release fn: one RELEASE event per
@@ -467,7 +493,7 @@ class Engine:
         from repro.tiered.kvcache import INVALID
         mpp = tcfg.max_pages_per_seq
 
-        def fn(state, lane, fl, step, tenant):
+        def engine_release_rec(state, lane, fl, step, tenant):
             lt0 = state.caches.leaf_table[0]
             ids = lane * mpp + jnp.arange(mpp, dtype=jnp.int32)
             held = lt0[ids] != INVALID
@@ -477,7 +503,7 @@ class Engine:
                 score=state.caches.touch[0][ids])
             return backend.release(state, lane), fl
 
-        return fn
+        return engine_release_rec
 
     def _refresh_lane_tenants(self, lanes) -> None:
         """Update the host-side lane -> tenant-index mirror from the live
@@ -525,22 +551,24 @@ class Engine:
         dispatches back-to-back with the next decode step) or, crucially,
         in ``release_lane`` BEFORE any release: every plan lands before
         the next metadata mutation, so the event sequence — and therefore
-        every counter — is identical to the synchronous pass."""
+        every counter — is identical to the synchronous pass.  Callers run
+        it inside the ``maintain_apply`` span."""
         if self._pending_plan is None:
             return state
         plan, plan_step = self._pending_plan
-        with self.tracer.span("maintain_apply", step=self.steps):
-            if self._fl is not None:
-                state, self._fl = self._rec_apply_fn(
-                    state, plan, self._fl, jnp.int32(plan_step),
-                    self._lane_tenant())
-            else:
-                state = self._apply_fn(state, plan)
         self._pending_plan = None
+        if self._fl is not None:
+            state, self._fl = self._rec_apply_fn(
+                state, plan, self._fl, jnp.int32(plan_step),
+                self._lane_tenant())
+        else:
+            state = self._apply_fn(state, plan)
+        del plan
         if overlapped:
             self.maintain_overlaps += 1
         # materialise the snapshot NOW: the donated next step reuses the
-        # state's buffers, so a live reference would read freed memory
+        # state's buffers, so a live reference would read freed memory.
+        # This host read waits for the apply
         self._bw_log.append((np.asarray(state.caches.promo_pages),
                              np.asarray(state.caches.demo_pages)))
         return state
@@ -552,7 +580,9 @@ class Engine:
         against pre-release residency, so applying after the release
         would resurrect the dead lane's pages."""
         if self._tiered:
-            state = self._flush_maintain(state)
+            if self._pending_plan is not None:
+                with self.tracer.span("maintain_apply", step=self.steps):
+                    state = self._flush_maintain(state)
             with self.tracer.span("release", lane=lane):
                 if self._fl is not None:
                     self._refresh_lane_tenants(
@@ -605,7 +635,7 @@ class Engine:
         if C not in self._write_chunk_fns:
             backend = self.backend
 
-            def fn(state, lane, bk, bv, start, length):
+            def engine_write_chunk(state, lane, bk, bv, start, length):
                 L, _, _, KV, hd = bk.shape
                 k = jax.lax.dynamic_slice(
                     bk, (0, 0, start, 0, 0), (L, 1, C, KV, hd))[:, 0]
@@ -614,7 +644,7 @@ class Engine:
                 return backend.write_prefill_chunk(state, lane, k, v,
                                                    start, length)
 
-            self._write_chunk_fns[C] = jax.jit(fn)
+            self._write_chunk_fns[C] = jax.jit(engine_write_chunk)
 
         def call(state, lane, bk, bv, start, length):
             with self.tracer.span("prefill_chunk", lane=lane,
@@ -631,15 +661,17 @@ class Engine:
         eviction the admission forced) records an event from the install
         descriptors."""
         if n_pages not in self._admit_fns:
+            backend = self.backend
             if self._fl is None:
-                self._admit_fns[n_pages] = jax.jit(
-                    lambda s, ln, le: self.backend.admit_prefix(
-                        s, ln, le, n_pages))
+                def engine_admit_fast(s, ln, le):
+                    return backend.admit_prefix(s, ln, le, n_pages)
+
+                self._admit_fns[n_pages] = jax.jit(engine_admit_fast)
             else:
-                backend = self.backend
                 mpp = backend.tcfg.max_pages_per_seq
 
-                def fn(s, ln, le, fl, step, lane_tenant, np_=n_pages):
+                def engine_admit_fast_rec(s, ln, le, fl, step, lane_tenant,
+                                          np_=n_pages):
                     touch0 = s.caches.touch[0]
                     s, pdesc = backend.admit_prefix_desc(s, ln, le, np_)
 
@@ -658,7 +690,7 @@ class Engine:
                              pdesc["cb2_dst"], pdesc["cb2_en"])
                     return s, fl
 
-                self._admit_fns[n_pages] = jax.jit(fn)
+                self._admit_fns[n_pages] = jax.jit(engine_admit_fast_rec)
         with self.tracer.span("admit_fast", lane=lane, pages=n_pages):
             if self._fl is None:
                 return self._admit_fns[n_pages](state, jnp.int32(lane),
@@ -672,9 +704,12 @@ class Engine:
     def build_maintain_tenants(self, pols: tuple, quotas: tuple):
         """Compile the multi-tenant maintenance pass against a static
         tenant partition (called once by the QoS scheduler at bind)."""
-        self._maintain_tenants = jax.jit(
-            lambda s, lt: self.backend.maintain_tenants(s, lt, pols,
-                                                        quotas))
+        backend = self.backend
+
+        def engine_maintain_tenants(s, lt):
+            return backend.maintain_tenants(s, lt, pols, quotas)
+
+        self._maintain_tenants = jax.jit(engine_maintain_tenants)
 
     def note_prefill_token(self, req: Request, tok: int, pos: int):
         """Credit a token decoded from prefill logits (the chunked
@@ -710,13 +745,13 @@ class Engine:
         if P not in self._prefill_fns:
             cfg, backend = self.cfg, self.backend
 
-            def fn(params, state, lane, tokens, length):
+            def engine_prefill(params, state, lane, tokens, length):
                 _, _, (k, v) = forward(cfg, params, {"tokens": tokens},
                                        collect_cache=True)
                 return backend.write_prefill(state, lane, k[:, 0], v[:, 0],
                                              length)
 
-            self._prefill_fns[P] = jax.jit(fn)
+            self._prefill_fns[P] = jax.jit(engine_prefill)
         return self._prefill_fns[P]
 
     def prefill_lane(self, state, lane: int, req: Request):
@@ -744,6 +779,12 @@ class Engine:
     # -- decode loop ------------------------------------------------------
 
     def run(self, log: Callable[[str], None] = lambda s: None) -> list[Request]:
+        """Serve the queue to the end.  Every statement of an iteration
+        runs inside exactly one phase span of its ``step`` (DESIGN.md
+        §10): ``maintain_apply`` (the deferred apply), ``bucket``,
+        ``decode_step`` (dispatch only), ``maintain`` (the plan, or the
+        synchronous pass), ``sync`` (the host reads), ``harvest`` and
+        ``refill``."""
         ec = self.ec
         sched = self.scheduler
         obs, tracer = ec.obs, self.tracer
@@ -761,93 +802,109 @@ class Engine:
             self._fl = obs_flight.init(self._fl_cfg.capacity)
             self._flight_cache = None
             self._lane_tenant_np[:] = 0
+        # multi-tenant maintenance is always synchronous: the tenant map
+        # can go stale across a deferral, and its moves are not
+        # flight-recorded (the plan has no single-descriptor pass)
+        tenants = hasattr(self, "_maintain_tenants")
 
         with profiler_trace(obs.profiler_dir if obs else None):
-            state, tokens = sched.refill(state, tokens, lanes, finished)
-            while any(l is not None for l in lanes):
-                self._refresh_lane_tenants(lanes)
-                # a plan deferred at the last hook applies now, its
-                # dispatch overlapping this step's host-side work
-                state = self._flush_maintain(state, overlapped=True)
-                step_fn = self._step_fn(self._live_bucket(state))
-                with tracer.span("decode_step", step=self.steps):
-                    logits, state = step_fn(self.params, state, tokens)
-                    tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                self.steps += 1
-                if self._tiered and self.steps % ec.maintain_every == 0:
-                    if ec.overlap_maintain \
-                            and not hasattr(self, "_maintain_tenants"):
-                        # double-buffered: plan now (scores + top-k only),
-                        # defer the pool moves to the next decode step.
-                        # The span keeps the canonical "maintain" name —
-                        # the §10 trace contract — with the apply half
-                        # showing up as "maintain_apply" under the next
-                        # decode step.  The plan carries its hook step so
-                        # the deferred apply's flight events stamp the
-                        # decision time (identical to the sync stream)
-                        with tracer.span("maintain", step=self.steps,
-                                         phase="plan"):
-                            self._pending_plan = (self._plan_fn(state),
-                                                  self.steps)
-                    elif self._fl is not None \
-                            and not hasattr(self, "_maintain_tenants"):
-                        # synchronous with the recorder on: the same
-                        # plan+apply pair (run_scheduler_stacked IS
-                        # apply(plan) — bit-identical), tee'd through the
-                        # descriptor recorder
-                        with tracer.span("maintain", step=self.steps):
-                            state, self._fl = self._rec_apply_fn(
-                                state, self._plan_fn(state), self._fl,
-                                jnp.int32(self.steps),
-                                self._lane_tenant())
-                        self._bw_log.append(
-                            (np.asarray(state.caches.promo_pages),
-                             np.asarray(state.caches.demo_pages)))
-                    else:
-                        # synchronous (multi-tenant maintenance always is:
-                        # the tenant map can go stale across a deferral;
-                        # its moves are not flight-recorded — the plan
-                        # has no single-descriptor pass)
-                        with tracer.span("maintain", step=self.steps):
-                            state = sched.maintain(state)
-                        self._bw_log.append(
-                            (np.asarray(state.caches.promo_pages),
-                             np.asarray(state.caches.demo_pages)))
-                if self.logits_log is not None:
-                    self.logits_log.append(np.asarray(logits))
-                nxt = np.asarray(tokens)
-                pos = np.asarray(state.pos)
-                now = time.time()
-                for i, r in enumerate(lanes):
-                    # lanes mid-chunk-ingest are parked: no token this
-                    # step; a request finished by its prefill token
-                    # (max_new == 1) must not harvest a stray extra one
-                    if r is None or r.done or not sched.is_decoding(i):
-                        continue
-                    if not r.tokens:
-                        r.first_token_at = now
-                    r.tokens.append(int(nxt[i]))
-                    r.token_times.append(now)
-                    self._tokens_out += 1
-                    if len(r.tokens) >= r.max_new \
-                            or int(pos[i]) >= ec.max_len - 1:
-                        r.done = True
-                        # each request's completion stamps ITS OWN clock —
-                        # latency is measured from its own enqueue time, not
-                        # the batch wave's anchor
-                        r.done_at = now
-                        if self.slo is not None:
-                            self.slo.observe(r.tenant_id,
-                                             latency_ms=1e3 * r.latency,
-                                             ttft_ms=1e3 * r.ttft)
-                if self.hub is not None \
-                        and self.steps % obs.sample_every == 0:
-                    self._sample(state, lanes, len(finished))
-                if self.steps % 16 == 0:
-                    log(f"[engine] step {self.steps}, "
-                        f"queue={len(self.queue)}, done={len(finished)}")
+            with tracer.span("refill", step=self.steps):
                 state, tokens = sched.refill(state, tokens, lanes, finished)
-            state = self._flush_maintain(state)   # a last hook may be open
+                self._refresh_lane_tenants(lanes)
+            while any(l is not None for l in lanes):
+                with tracer.step(self.steps):
+                    if self._pending_plan is not None:
+                        # a plan deferred at the last hook applies now,
+                        # its dispatch overlapping this step's host work
+                        with tracer.span("maintain_apply", step=self.steps):
+                            state = self._flush_maintain(state,
+                                                         overlapped=True)
+                    with tracer.span("bucket", step=self.steps):
+                        step_fn = self._step_fn(self._live_bucket(state))
+                    with tracer.span("decode_step", step=self.steps):
+                        logits, state = step_fn(self.params, state, tokens)
+                        tokens = jnp.argmax(logits, axis=-1).astype(
+                            jnp.int32)
+                        self.steps += 1
+                    if self._tiered and self.steps % ec.maintain_every == 0:
+                        if ec.overlap_maintain and not tenants:
+                            # double-buffered: plan now (scores + top-k
+                            # only), defer the pool moves to the next
+                            # decode step.  The span keeps the canonical
+                            # "maintain" name — the §10 trace contract —
+                            # with the apply half showing up as
+                            # "maintain_apply" at the top of the next step.
+                            # The plan carries its hook step so the
+                            # deferred apply's flight events stamp the
+                            # decision time (identical to the sync stream)
+                            with tracer.span("maintain", step=self.steps,
+                                             phase="plan"):
+                                self._pending_plan = (self._plan_fn(state),
+                                                      self.steps)
+                        else:
+                            with tracer.span("maintain", step=self.steps):
+                                if self._fl is not None and not tenants:
+                                    # synchronous with the recorder on: the
+                                    # same plan+apply pair
+                                    # (run_scheduler_stacked IS apply(plan)
+                                    # — bit-identical), tee'd through the
+                                    # descriptor recorder
+                                    state, self._fl = self._rec_apply_fn(
+                                        state, self._plan_fn(state),
+                                        self._fl, jnp.int32(self.steps),
+                                        self._lane_tenant())
+                                else:
+                                    state = sched.maintain(state)
+                                self._bw_log.append(
+                                    (np.asarray(state.caches.promo_pages),
+                                     np.asarray(state.caches.demo_pages)))
+                    with tracer.span("sync", step=self.steps):
+                        if self.logits_log is not None:
+                            self.logits_log.append(np.asarray(logits))
+                        del logits
+                        nxt = np.asarray(tokens)
+                        pos = np.asarray(state.pos)
+                    with tracer.span("harvest", step=self.steps):
+                        now = time.time()
+                        for i, r in enumerate(lanes):
+                            # lanes mid-chunk-ingest are parked: no token
+                            # this step; a request finished by its prefill
+                            # token (max_new == 1) must not harvest a stray
+                            # extra one
+                            if r is None or r.done \
+                                    or not sched.is_decoding(i):
+                                continue
+                            if not r.tokens:
+                                r.first_token_at = now
+                            r.tokens.append(int(nxt[i]))
+                            r.token_times.append(now)
+                            self._tokens_out += 1
+                            if len(r.tokens) >= r.max_new \
+                                    or int(pos[i]) >= ec.max_len - 1:
+                                r.done = True
+                                # each request's completion stamps ITS OWN
+                                # clock — latency is measured from its own
+                                # enqueue time, not the batch wave's anchor
+                                r.done_at = now
+                                if self.slo is not None:
+                                    self.slo.observe(
+                                        r.tenant_id,
+                                        latency_ms=1e3 * r.latency,
+                                        ttft_ms=1e3 * r.ttft)
+                        if self.hub is not None \
+                                and self.steps % obs.sample_every == 0:
+                            self._sample(state, lanes, len(finished))
+                        if self.steps % 16 == 0:
+                            log(f"[engine] step {self.steps}, "
+                                f"queue={len(self.queue)}, "
+                                f"done={len(finished)}")
+                    with tracer.span("refill", step=self.steps):
+                        state, tokens = sched.refill(state, tokens, lanes,
+                                                     finished)
+                        self._refresh_lane_tenants(lanes)
+            if self._pending_plan is not None:   # a last hook may be open
+                with tracer.span("maintain_apply", step=self.steps):
+                    state = self._flush_maintain(state)
         self.final_state = state            # introspection (tests, examples)
         if self.hub is not None:
             self._finalize_obs(state, lanes, finished)

@@ -234,16 +234,18 @@ class ChunkedScheduler:
 
     def refill(self, state, tokens, lanes, finished):
         eng, ec = self.eng, self.ec
+        span = eng.tracer.span
         # 1. recycle finished lanes
         for i in range(ec.batch):
             r = lanes[i]
             if r is not None and r.done:
-                finished.append(r)
-                self.book.finish(r)
-                lanes[i] = None
-                self.lane_tenant[i] = -1
-                self._admitted[i] = 0
-                state = eng.release_lane(state, i)
+                with span("release", cat="sched", rid=r.rid, lane=i):
+                    finished.append(r)
+                    self.book.finish(r)
+                    lanes[i] = None
+                    self.lane_tenant[i] = -1
+                    self._admitted[i] = 0
+                    state = eng.release_lane(state, i)
         # 2. chunk budget: advance ONE in-flight ingest by one chunk
         #    (round-robin across ingesting lanes, so several long prompts
         #    share the budget instead of serialising)
@@ -251,7 +253,9 @@ class ChunkedScheduler:
         if live:
             lane = live[self._rr % len(live)]
             self._rr += 1
-            state, tokens = self._advance(state, tokens, lane)
+            with span("advance", cat="sched",
+                      rid=self.ingests[lane].req.rid, lane=lane):
+                state, tokens = self._advance(state, tokens, lane)
         # 3. admit queued requests to free lanes (QoS picker)
         for i in range(ec.batch):
             if lanes[i] is not None:
@@ -260,12 +264,14 @@ class ChunkedScheduler:
             if req is None:
                 break
             lanes[i] = req
-            state, tokens = self._admit(state, tokens, i, req)
+            with span("admit", cat="sched", rid=req.rid, lane=i):
+                state, tokens = self._admit(state, tokens, i, req)
         # 4. park empty and still-ingesting lanes
-        idle = np.array([lanes[i] is None or i in self.ingests
-                         for i in range(ec.batch)])
-        if idle.any():
-            state = eng.park_idle(state, idle)
+        with span("park", cat="sched"):
+            idle = np.array([lanes[i] is None or i in self.ingests
+                             for i in range(ec.batch)])
+            if idle.any():
+                state = eng.park_idle(state, idle)
         return state, tokens
 
     def maintain(self, state):

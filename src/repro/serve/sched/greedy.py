@@ -56,12 +56,14 @@ class GreedyScheduler:
         from the queue (real one-shot prefill), park still-empty lanes at
         pos = -1 so they neither write nor read nor heat anything."""
         eng, ec = self.eng, self.ec
+        span = eng.tracer.span
         for i in range(ec.batch):
             r = lanes[i]
             if r is not None and r.done:
-                finished.append(r)
-                lanes[i] = None
-                state = eng.release_lane(state, i)
+                with span("release", cat="sched", rid=r.rid, lane=i):
+                    finished.append(r)
+                    lanes[i] = None
+                    state = eng.release_lane(state, i)
             if lanes[i] is None:
                 req = self._pick(self.active_bucket)
                 if req is None:
@@ -69,12 +71,14 @@ class GreedyScheduler:
                 if self.active_bucket is None:
                     self.active_bucket = req.max_new
                 lanes[i] = req
-                req.admitted_at = time.time()
-                state, tok = eng.prefill_lane(state, i, req)
-                tokens = tokens.at[i].set(tok)
-        idle = np.array([l is None for l in lanes])
-        if idle.any():
-            state = eng.park_idle(state, idle)
+                with span("admit", cat="sched", rid=req.rid, lane=i):
+                    req.admitted_at = time.time()
+                    state, tok = eng.prefill_lane(state, i, req)
+                    tokens = tokens.at[i].set(tok)
+        with span("park", cat="sched"):
+            idle = np.array([l is None for l in lanes])
+            if idle.any():
+                state = eng.park_idle(state, idle)
         if idle.all() and not self.queue:
             self.active_bucket = None       # the wave drained: re-anchor
         return state, tokens

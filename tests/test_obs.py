@@ -295,7 +295,6 @@ def test_tracer_perfetto_json(tmp_path):
     with tr.span("maintain", step=2):
         pass
     tr.counter("trimma_pages", {"fast_resident": 4.0}, ts=10.0)
-    tr.instant("drain")
     path = tr.save(str(tmp_path / "t.json"))
     doc = json.load(open(path))
     evs = doc["traceEvents"]
