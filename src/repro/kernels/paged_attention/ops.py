@@ -47,10 +47,11 @@ def paged_attention_fused_op(q, fast_k, fast_v, slow_k, slow_v, entries,
 
     Both backends route by the same forward map: ``entries`` [B,npages]
     (leaf rows — >= 0 names a page's fast slot, < 0 means the identity
-    slow home; the TPU index maps route each page's DMA by it, the CPU
-    oracle gathers by it).  New rows are cast to the pool dtype *here*
-    so the attended values are bitwise the values a prior
-    ``append_token`` would have stored.
+    slow home; the TPU index maps fetch each attended page from that one
+    tier, the CPU oracle gathers by it).  New rows are cast to the pool
+    dtype *here* so the attended values are bitwise the values a prior
+    ``append_token`` would have stored.  A parked lane's rows are
+    meaningless (0 from the kernel, which reads nothing for it).
 
     ``entries`` may be sliced to the live-page bucket (its second dim is
     the number of pages attended, DESIGN.md §11): both backends read only

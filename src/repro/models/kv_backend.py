@@ -332,6 +332,8 @@ class TieredBackend:
         st0 = tk.record_touches(cfg, st0,
                                 srv.page_table(cfg, st0).reshape(-1),
                                 lv.reshape(-1))
+        # the pages each layer's fused read fetches; L layers sum them
+        st0 = srv.record_fetches(cfg, st0, pos, 1, entries.shape[1])
         # re-broadcast ONLY the metadata this pass actually changed
         # (identity against the layer-0 slice finds them); untouched
         # fields keep the input's stacked arrays, so the hot path never

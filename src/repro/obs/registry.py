@@ -92,6 +92,7 @@ TIERED_FIELDS = {
     "migrations": "trimma_migrations_total",
     "demotions": "trimma_demotions_total",
     "forced_evict": "trimma_forced_evictions_total",
+    "attn_pages": "trimma_attn_pages_fetched_total",
 }
 
 # legacy short key (TieredServer.counters / TieredBackend.counters) ->

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from repro.kernels.paged_attention.ops import (paged_attention_fused_op,
                                                paged_attention_op,
                                                paged_attention_split_op)
+from repro.kernels.paged_attention.paged_attention import attended_pages
 from repro.obs import metrics as obs_metrics
 from repro.tiered import kvcache as tk
 
@@ -62,6 +63,17 @@ def attend(cfg: tk.TieredConfig, st: tk.TieredState, q, seq_lens,
                                    st.slow_k, st.slow_v, table, seq_lens,
                                    impl=impl)
     return out, st
+
+
+def record_fetches(cfg: tk.TieredConfig, st: tk.TieredState, pos,
+                   k_tok: int, n_pages: int) -> tk.TieredState:
+    """Count the pages one fused-kernel call fetches
+    (``trimma_attn_pages_fetched_total``): each lane's pages under
+    ``pos + k_tok`` within the ``n_pages`` attended, none for a parked
+    lane — the kernel's own skip predicate.  Over ``B * n_pages`` it is
+    the share of the bucket the kernel reads."""
+    n = attended_pages(pos, k_tok, cfg.page_tokens, n_pages)
+    return st._replace(attn_pages=st.attn_pages + n.sum(dtype=jnp.int32))
 
 
 def attend_tokens(cfg: tk.TieredConfig, st: tk.TieredState, q, k_new,
@@ -98,6 +110,7 @@ def attend_tokens(cfg: tk.TieredConfig, st: tk.TieredState, q, k_new,
                                    k_new, v_new, pos, impl=impl)
     st = tk.append_tokens(cfg, st, jnp.arange(cfg.n_seqs, dtype=jnp.int32),
                           k_new, v_new, pos)
+    st = record_fetches(cfg, st, pos, K, entries.shape[1])
     lv = live_mask(cfg, jnp.where(pos >= 0, pos + K, 0))
     st = tk.record_reads(cfg, st, page_table(cfg, st).reshape(-1),
                          lv.reshape(-1))

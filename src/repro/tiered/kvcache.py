@@ -86,6 +86,9 @@ register(
     MetricSpec("trimma_dev_table_hits_total", "counter",
                "live lookup lanes served from the cached device page "
                "table (zero iRC probes, zero iRT walks)"),
+    MetricSpec("trimma_attn_pages_fetched_total", "counter",
+               "pages the fused decode kernel fetched, each from the one "
+               "tier its leaf entry names (summed over layers)"),
     MetricSpec("trimma_fast_resident_pages", "gauge",
                "pages currently resident in the fast pool"),
     MetricSpec("trimma_metadata_pages", "gauge",
@@ -209,6 +212,7 @@ class TieredState(NamedTuple):
                                  # demo_pages counts ALL fast->slow
                                  # copy-backs (int32-safe page counts)
     dev_hits: jnp.ndarray        # live lookup lanes served from dev_table
+    attn_pages: jnp.ndarray      # pages the fused decode kernel fetched
 
 
 _RC_KEYS = ("nid_tag", "nid_val", "nid_fifo", "id_tag", "id_bits", "id_fifo")
@@ -277,7 +281,7 @@ def init_state(cfg: TieredConfig) -> TieredState:
         irc_id_hits=z((), jnp.int32), migrations=z((), jnp.int32),
         demotions=z((), jnp.int32), forced_evict=z((), jnp.int32),
         promo_pages=z((), jnp.int32), demo_pages=z((), jnp.int32),
-        dev_hits=z((), jnp.int32),
+        dev_hits=z((), jnp.int32), attn_pages=z((), jnp.int32),
         **rc,
     )
 

@@ -179,16 +179,7 @@ def test_paged_attention_split_matches_concat_bitwise():
         np.asarray(paged_attention(q, uk, uv, pt, sl, interpret=True)))
 
 
-@pytest.mark.parametrize("B,K,KV,G,hd,page,np_total,n_pages,fast_slots", [
-    (2, 1, 2, 3, 64, 16, 8, 8, 4),
-    (3, 4, 2, 2, 64, 16, 8, 4, 6),      # live-page bucket < table width
-])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_attention_fused_sweep(B, K, KV, G, hd, page, np_total,
-                                     n_pages, fast_slots, dtype):
-    """Fused k-token append+attend kernel vs its oracle: fast-routed and
-    slow-home pages mixed, a parked lane, and new rows that overlay
-    positions inside the attended prefix."""
+def _fused_args(B, K, KV, G, hd, page, np_total, n_pages, fast_slots, dtype):
     ks = [jax.random.fold_in(KEY, i) for i in range(8)]
     q = jax.random.normal(ks[0], (B, K, KV, G, hd), dtype)
     fk = jax.random.normal(ks[1], (fast_slots, KV, page, hd), dtype)
@@ -201,16 +192,57 @@ def test_paged_attention_fused_sweep(B, K, KV, G, hd, page, np_total,
     entries = np.full((B, n_pages), -1, np.int32)
     lanes = np.asarray(jax.random.permutation(ks[7], B * n_pages))
     entries.reshape(-1)[lanes[:fast_slots]] = np.arange(fast_slots)
-    pos = np.asarray([n_pages * page - K - 3] + [5] * (B - 1), np.int32)
+    return q, fk, fv, sk, sv, jnp.asarray(entries), kn, vn
+
+
+@pytest.mark.parametrize("B,K,KV,G,hd,page,np_total,n_pages,fast_slots", [
+    (2, 1, 2, 3, 64, 16, 8, 8, 4),
+    (3, 4, 2, 2, 64, 16, 8, 4, 6),      # live-page bucket < table width
+    # granite widths; a bucket of 22 pages is no multiple of the block
+    (4, 1, 8, 3, 64, 16, 24, 22, 16),
+    # qwen2 widths, 4 new tokens; 18 pages, no multiple of the block
+    (4, 4, 4, 7, 128, 16, 24, 18, 12),
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_paged_attention_fused_sweep(B, K, KV, G, hd, page, np_total,
+                                     n_pages, fast_slots, dtype):
+    """Fused k-token append+attend kernel vs its oracle: fast-routed and
+    slow-home pages mixed, new rows that overlay positions inside the
+    attended prefix, and lanes of every length: lane 0 reaches the
+    bucket's last page, lane 1 holds one page (less than a block), a
+    middle lane ends mid-bucket, and the last lane is parked (its rows
+    are 0: it fetches and computes nothing)."""
+    args = _fused_args(B, K, KV, G, hd, page, np_total, n_pages,
+                       fast_slots, dtype)
+    span = n_pages * page
+    pos = np.asarray([span - K - 3] + [5 + (b - 1) * (span // 2)
+                                       for b in range(1, B)], np.int32)
     pos[-1] = -1                                    # parked lane
-    args = (q, fk, fv, sk, sv, jnp.asarray(entries), kn, vn,
-            jnp.asarray(pos))
+    args = args + (jnp.asarray(pos),)
     out = paged_attention_fused(*args, interpret=True)
     ref = paged_attention_fused_ref(*args)
     live = pos >= 0
     np.testing.assert_allclose(np.asarray(out, np.float32)[live],
                                np.asarray(ref, np.float32)[live],
                                **_tol(dtype))
+    np.testing.assert_array_equal(np.asarray(out, np.float32)[~live], 0.0)
+
+
+def test_paged_attention_fused_bucket_bitwise():
+    """The kernel at the full table width and at the live-page bucket
+    runs the same operations: blocks past every lane's last attended
+    page fetch and compute nothing, so the outputs are bitwise equal."""
+    B, K, KV, G, hd, page, np_total = 3, 1, 8, 3, 64, 16, 32
+    args = _fused_args(B, K, KV, G, hd, page, np_total, np_total, 12,
+                       jnp.float32)
+    q, fk, fv, sk, sv, entries, kn, vn = args
+    n_pages = 16
+    pos = jnp.asarray([n_pages * page - 2, 70, -1], jnp.int32)
+    full = paged_attention_fused(q, fk, fv, sk, sv, entries, kn, vn, pos,
+                                 interpret=True)
+    bucket = paged_attention_fused(q, fk, fv, sk, sv, entries[:, :n_pages],
+                                   kn, vn, pos, interpret=True)
+    np.testing.assert_array_equal(np.asarray(full), np.asarray(bucket))
 
 
 # ---------------------------------------------------------------------------

@@ -110,6 +110,38 @@ def test_attend_tokens_parked_lane_reads_nothing():
     assert int(st2.touch[half:].sum()) == 0
 
 
+@pytest.mark.parametrize("n_pages", [None, 8])
+def test_attend_tokens_counts_fetched_pages(n_pages):
+    """trimma_attn_pages_fetched_total after one fused call: each lane's
+    pages under pos + K (the new rows included), none for a parked lane,
+    at the full table width and at a live-page bucket alike."""
+    cfg = _cfg(n_seqs=3)
+    key = jax.random.key(4)
+    st = _filled(cfg, key)
+    K = 2
+    q, kn, vn = _qkv(cfg, jax.random.fold_in(key, 9), K)
+    pos = jnp.asarray([9, -1, 29], jnp.int32)      # pages: 3, 0, 8
+    _, st2 = srv.attend_tokens(cfg, st, q, kn, vn, pos, n_pages=n_pages)
+    m = srv.metrics(cfg, st2)
+    assert int(m["trimma_attn_pages_fetched_total"]) == 3 + 0 + 8
+
+
+def test_decode_step_counts_fetched_pages_per_layer():
+    """Layer-stacked backend: one decode step's metadata pass counts each
+    lane's attended pages once per layer (0 for a parked lane)."""
+    from repro.configs import get_config, reduce_for_smoke
+    from repro.models.kv_backend import TieredBackend
+
+    cfg = reduce_for_smoke(get_config("llama3-8b"))
+    B, max_len = 3, 64
+    bk = TieredBackend(cfg, B, max_len, page_tokens=8, fast_data_slots=4)
+    state = bk.init_state(B, max_len)
+    pos = jnp.asarray([20, -1, 7], jnp.int32)      # pages: 3, 0, 1
+    caches, _ = bk.begin_step(state.caches, pos, n_pages=4)
+    m = bk.metrics(state._replace(caches=caches))
+    assert m["trimma_attn_pages_fetched_total"] == cfg.n_layers * (3 + 0 + 1)
+
+
 def _np_attend_tokens(cfg, st, q, kn, vn, pos):
     """Plain float64 NumPy reference of the k-token read on a store with
     no fast-resident page: each lane's pages from its identity slow

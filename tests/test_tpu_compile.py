@@ -69,6 +69,38 @@ def test_fused_paged_attention_compiles(one_chip, k_tok):
     assert "tpu_custom_call" in c.as_text()
 
 
+# the benchmark cells' serving shapes: lanes, positions per lane, fast
+# slots, and the widths of granite-moe-3b-a800m and qwen2-7b
+CELL_SHAPES = {
+    "granite-longctx": dict(B=4, max_len=4096, fast=128, KV=8, G=3, hd=64),
+    "qwen2-chat": dict(B=16, max_len=2048, fast=256, KV=4, G=7, hd=128),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_SHAPES))
+def test_fused_paged_attention_compiles_at_cell_shapes(one_chip, cell):
+    """The fused kernel at each cell's full table width: its multi-page
+    blocks and their double buffers fit the scoped vector memory."""
+    from repro.kernels.paged_attention.paged_attention import \
+        paged_attention_fused
+    c = CELL_SHAPES[cell]
+    B, KV, hd = c["B"], c["KV"], c["hd"]
+    npages = c["max_len"] // PAGE
+    bf16, i32 = jnp.bfloat16, jnp.int32
+    pool = (c["fast"], KV, PAGE, hd)
+    homes = (B * npages, KV, PAGE, hd)
+    new = (B, 1, KV, hd)
+    compiled = _compile(paged_attention_fused,
+                        _sds(one_chip, (B, 1, KV, c["G"], hd), bf16),
+                        _sds(one_chip, pool, bf16), _sds(one_chip, pool, bf16),
+                        _sds(one_chip, homes, bf16),
+                        _sds(one_chip, homes, bf16),
+                        _sds(one_chip, (B, npages), i32),
+                        _sds(one_chip, new, bf16), _sds(one_chip, new, bf16),
+                        _sds(one_chip, (B,), i32))
+    assert "tpu_custom_call" in compiled.as_text()
+
+
 def test_remap_gather_compiles(one_chip):
     from repro.kernels.remap_gather.remap_gather import remap_gather
     cfg = get_config(GRANITE)
